@@ -16,10 +16,12 @@ jitlint rule JL008 (timing-discipline) statically rejects raw
 ``time.perf_counter()`` pairs around device work; routing through ``Timed``
 (whose ``sync`` is the one sanctioned blocking point) is the fix it suggests.
 
-``profile=True`` additionally wraps the section in a
-``jax.profiler.TraceAnnotation`` so engine spans line up with XLA's own
-timeline when serving runs under ``--profile-dir``
-(:func:`profile_trace`).
+``Timed`` itself records nothing.  The engine opens every section,
+program calls and host phases alike, through one span call,
+:meth:`repro.obs.Tracer.section`: a ``Timed`` on the tracer's clock that
+also lands in the tracer's ring and, when the engine is profiling, in a
+``jax.profiler.TraceAnnotation`` of the same name (:func:`profile_trace`),
+so the two timelines carry the same spans.
 """
 from __future__ import annotations
 
@@ -38,22 +40,15 @@ class Timed:
     dispatch).
     """
 
-    __slots__ = ("name", "profile", "t0", "t1", "dur", "synced", "_clock",
-                 "_ann")
+    __slots__ = ("name", "t0", "t1", "dur", "synced", "_clock")
 
-    def __init__(self, name: str = "", *, profile: bool = False,
-                 clock=time.perf_counter):
+    def __init__(self, name: str = "", *, clock=time.perf_counter):
         self.name = name
-        self.profile = profile
         self._clock = clock
         self.t0 = self.t1 = self.dur = 0.0
         self.synced = False
-        self._ann = None
 
     def __enter__(self) -> "Timed":
-        if self.profile:
-            self._ann = jax.profiler.TraceAnnotation(self.name or "timed")
-            self._ann.__enter__()
         self.t0 = self._clock()
         return self
 
@@ -65,9 +60,6 @@ class Timed:
         return out
 
     def __exit__(self, *exc) -> bool:
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
-            self._ann = None
         self.t1 = self._clock()
         self.dur = self.t1 - self.t0
         return False

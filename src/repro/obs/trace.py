@@ -16,6 +16,16 @@ Event vocabulary (Chrome trace-event ``ph`` codes; see docs/observability.md):
   * ``C`` counter         — per-tick series (queue depth, slot occupancy,
                             KV-pool in-use/cached, per shard)
 
+One span call, :meth:`Tracer.section`, opens every section the engine
+times, program calls and host phases alike.  It is a
+:class:`~repro.obs.timing.Timed` on the tracer's clock (so device work
+still syncs before its closing stamp) that appends one ``X`` event at exit
+and, with ``profile=True``, also wraps the body in a
+``jax.profiler.TraceAnnotation`` of the same name: the span then shows on
+the profiler's timeline too, carrying its ring start as ``t_us`` metadata
+(microseconds from the tracer's epoch, the export's time base), so
+ring-only events can be placed on the device trace's clock.
+
 Tracks are integer ``tid``s named via :meth:`Tracer.set_track` (exported as
 ``thread_name`` metadata): the engine uses track 0 for queue-level request
 events, one track per slot, and one for engine-wide spans.  Timestamps are
@@ -30,6 +40,10 @@ import json
 import time
 from pathlib import Path
 
+import jax
+
+from .timing import Timed
+
 #: default ring capacity — ~4 MB of tuples, tens of thousands of ticks
 DEFAULT_CAPACITY = 65536
 
@@ -41,7 +55,8 @@ class Tracer:
     benchmarks/serve_bench.py measures exactly this switch); a disabled
     tracer's emit methods return immediately.  ``clock`` is the shared
     monotonic clock — the engine stamps *all* its times through
-    :meth:`now` so spans, stats, and TTFTs live on one timeline.
+    :meth:`now` and :meth:`section` (program calls and host phases share
+    that one span call) so spans, stats, and TTFTs live on one timeline.
     """
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY, *,
@@ -59,6 +74,15 @@ class Tracer:
     # ------------------------------------------------------------------ clock
     def now(self) -> float:
         return self.clock()
+
+    # ------------------------------------------------------------------ spans
+    def section(self, name: str, tid: int | None, args: tuple = (), *,
+                profile: bool = False) -> "Section":
+        """The one span call: a :class:`Section` named ``name`` on track
+        ``tid`` (no ring event when ``tid`` is None: the caller emits its
+        own from the stamps), with a profiler annotation when ``profile``
+        is set — read per call, so a flag set after construction holds."""
+        return Section(self, name, tid, args, profile)
 
     # ----------------------------------------------------------------- tracks
     def set_track(self, tid: int, name: str) -> None:
@@ -160,3 +184,38 @@ class Tracer:
 
     def save(self, path, other_data: dict | None = None) -> None:
         Path(path).write_text(self.dumps(other_data) + "\n")
+
+
+class Section(Timed):
+    """A :class:`Timed` section that lands in both sinks: one ring ``X``
+    event at exit (``args`` may be set inside the block, for what is known
+    only at its end), and when profiling a ``TraceAnnotation`` of the same
+    name inside the stamps, with the start as ``t_us`` metadata."""
+
+    __slots__ = ("tracer", "tid", "args", "profile", "_ann")
+
+    def __init__(self, tracer: Tracer, name: str, tid: int | None,
+                 args: tuple, profile: bool):
+        super().__init__(name, clock=tracer.clock)
+        self.tracer = tracer
+        self.tid = tid
+        self.args = args
+        self.profile = profile
+        self._ann = None
+
+    def __enter__(self) -> "Section":
+        super().__enter__()
+        if self.profile:
+            self._ann = jax.profiler.TraceAnnotation(
+                self.name, t_us=round(self.tracer._us(self.t0)))
+            self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        super().__exit__(*exc)
+        if self.tid is not None:
+            self.tracer.span(self.name, self.tid, self.t0, self.t1, self.args)
+        return False

@@ -75,12 +75,15 @@ The engine is *observable by default* (repro/obs): every request's lifecycle
 (submit → admit → prefill/chunk → decode → stall → finish/abort) lands in a
 ring-buffered :class:`~repro.obs.Tracer` — one track per slot, per-tick
 counter tracks, exportable as Chrome trace-event JSON via
-:meth:`ServeEngine.save_trace` — and every duration is stamped through
-:class:`~repro.obs.Timed`, which blocks on the program outputs first (JAX
-dispatch is async; an unsynchronized stamp times the enqueue, not the
-compute).  The engine never reads ``time.perf_counter`` directly: all stamps
-come from the tracer's clock, so spans, stats, and TTFTs share one timeline
-(statically enforced by jitlint JL008).  Aggregates go to the
+:meth:`ServeEngine.save_trace` — and every section, program call or host
+phase of a tick, goes through the tracer's one span call
+(:meth:`~repro.obs.Tracer.section`): a :class:`~repro.obs.Timed` whose
+program sections block on their outputs first (JAX dispatch is async; an
+unsynchronized stamp times the enqueue, not the compute), one ring event,
+and, when profiling, a profiler annotation of the same name.  The engine
+never reads ``time.perf_counter`` directly: all stamps come from the
+tracer's clock, so spans, stats, and TTFTs share one timeline (statically
+enforced by jitlint JL008).  Aggregates go to the
 ``EngineStats.metrics`` registry (log2 histograms + counters), serialized as
 the versioned ``obs`` section of ``summary()``.
 """
@@ -99,7 +102,7 @@ from ..core.accelerators import host_chip
 from ..models.attention import PagedKVCache
 from ..models.layout import MeshLayout
 from ..models.transformer import Model
-from ..obs import MetricsRegistry, ProgramRegistry, Timed, Tracer
+from ..obs import MetricsRegistry, ProgramRegistry, Tracer
 from ..obs.drift import drift_report, plan_predictions
 from .kvpool import PagedKVManager
 from .sampling import sample_tokens
@@ -181,6 +184,10 @@ class EngineStats:
     prefix_tokens_reused: int = 0
     blocks_copied: int = 0              # copy-on-write clones
     blocks_evicted: int = 0             # LRU evictions of cached blocks
+    # the KV manager's own work: radix nodes looked at by LRU eviction and
+    # walked by the admission supply check (kvpool work counters)
+    evict_scanned: int = 0
+    reclaim_walked: int = 0
     decode_stalls: int = 0              # slot-ticks frozen waiting for blocks
     # ---- sharded pool (mesh engines; kv_shards == 1 otherwise) ----
     kv_shards: int = 1
@@ -258,6 +265,8 @@ class EngineStats:
                 "prefix_tokens_reused": self.prefix_tokens_reused,
                 "blocks_copied": self.blocks_copied,
                 "blocks_evicted": self.blocks_evicted,
+                "evict_scanned": self.evict_scanned,
+                "reclaim_walked": self.reclaim_walked,
                 "decode_stalls": self.decode_stalls,
             }
             if self.kv_shards > 1:
@@ -382,8 +391,10 @@ class ServeEngine:
 
         ``tracer``: a :class:`repro.obs.Tracer`; default is a fresh enabled
         one (pass ``Tracer(enabled=False)`` to opt out).  ``profile=True``
-        wraps each timed section in a ``jax.profiler.TraceAnnotation`` so
-        XLA profiles line up with engine spans.
+        (an attribute, read per span) also opens every span — program calls
+        and the tick's host phases, through :meth:`Tracer.section` — as a
+        ``jax.profiler.TraceAnnotation``, so XLA profiles carry the same
+        spans as the ring.
 
         ``program_memory=True`` additionally AOT-compiles each program at
         warmup for its ``memory_analysis()`` temp/argument/output watermarks
@@ -562,11 +573,16 @@ class ServeEngine:
         if self.kv is not None:
             self.kv.set_block_bytes(pool_bytes // self.kv.pool.num_blocks)
         self.stats = EngineStats()
+        # the KV manager's work counters at the last counter sample
+        self._kv_work_seen = (0, 0)
         self._init_kv_stats()
 
-    def _timed(self, name: str) -> Timed:
-        """A Timed section on the tracer's clock (one shared timeline)."""
-        return Timed(name, profile=self.profile, clock=self.tracer.clock)
+    def _span(self, name: str, tid: int | None = None, args: tuple = ()):
+        """A section through the tracer's one span call (a synced-capable
+        ``Timed`` on its clock, one ring event on track ``tid``), with a
+        profiler annotation while the engine profiles: ``profile`` is read
+        per call, since a harness may set it after construction."""
+        return self.tracer.section(name, tid, args, profile=self.profile)
 
     def _slot_track(self, slot: int) -> int:
         """Tracer track id of ``slot`` (track_base-relative)."""
@@ -601,6 +617,7 @@ class ServeEngine:
 
     def reset_stats(self) -> None:
         self.stats = EngineStats()
+        self._kv_work_seen = (0, 0)
         if self.kv is not None:
             self.kv.reset_stats()
         self.programs.reset_observed()
@@ -636,12 +653,15 @@ class ServeEngine:
         st.prefix_tokens_reused = mgr.stats.prefix_tokens_reused
         st.blocks_copied = mgr.stats.blocks_copied
         st.blocks_evicted = mgr.blocks_evicted
+        st.evict_scanned = mgr.evict_scanned
+        st.reclaim_walked = mgr.reclaim_walked
 
     def _tick_counters(self, ts: float, busy: int) -> None:
         """Per-tick counter-track samples (queue depth, slot occupancy,
-        paged KV-pool in-use/cached, device-memory bytes) plus the memory
-        gauges — gauges update even untraced so ``summary()`` always carries
-        the latest occupancy in bytes."""
+        paged KV-pool in-use/cached and the radix nodes the KV manager
+        scanned or walked since the last sample, device-memory bytes) plus
+        the memory gauges — gauges update even untraced so ``summary()``
+        always carries the latest occupancy in bytes."""
         m = self.stats.metrics
         state_bytes = busy * self._slot_state_bytes
         m.gauge("active_state_bytes", "bytes").set(state_bytes)
@@ -655,8 +675,12 @@ class ServeEngine:
         tr.counter(p + "slots", ts, (("busy", busy),
                                      ("free", self.slots - busy)))
         if self.kv is not None:
-            tr.counter(p + "kv_blocks", ts, (("in_use", self.kv.in_use),
-                                             ("cached", self.kv.cached)))
+            work = (self.kv.evict_scanned, self.kv.reclaim_walked)
+            seen, self._kv_work_seen = self._kv_work_seen, work
+            tr.counter(p + "kv_blocks", ts, (
+                ("in_use", self.kv.in_use), ("cached", self.kv.cached),
+                ("evict_scanned", work[0] - seen[0]),
+                ("reclaim_walked", work[1] - seen[1])))
             if self.kv.shards > 1:
                 tr.counter(p + "kv_in_use_by_shard", ts, tuple(
                     (f"shard{i}", v)
@@ -742,7 +766,8 @@ class ServeEngine:
             matched = 0
             copy = None
             if self.kv is not None:
-                plan = self.kv.admit(slot, req.prompt)
+                with self._span("kv_admit", self._trk_engine):
+                    plan = self.kv.admit(slot, req.prompt)
                 if plan is None:
                     # pool can't cover the prompt yet: keep FIFO order and
                     # retry next tick (decode frees blocks as requests end)
@@ -778,7 +803,6 @@ class ServeEngine:
             members = groups[b]
             for i in range(0, len(members), self.max_prefill_batch):
                 self._prefill_group(b, members[i:i + self.max_prefill_batch])
-        self._sync_kv_stats()
         return admitted
 
     # ------------------------------------------------------ compiled programs
@@ -854,14 +878,13 @@ class ServeEngine:
                                      is_leaf=_is_paged)}
 
     def _run_copy(self, src: int, dst: int) -> None:
-        with self._timed("kv_copy") as tm:
+        with self._span("kv_copy", self._trk_engine,
+                        (("src", src), ("dst", dst))) as tm:
             self.states = self._copy(self.states,
                                      jnp.asarray(src, jnp.int32),
                                      jnp.asarray(dst, jnp.int32))
             tm.sync(self.states)
         self.programs.observe("copy", tm.dur, phase="kv", program="_copy")
-        self.tracer.span("kv_copy", self._trk_engine, tm.t0, tm.t1,
-                         (("src", src), ("dst", dst)))
 
     def _export_slot(self, pool_states, slot, table_row):
         """Pack slot ``slot`` into a self-contained handoff suitcase: the
@@ -959,7 +982,8 @@ class ServeEngine:
         slots_real = [slot for slot, _ in members]
         bt = self._tables_for(slots_real, nb)
         samp = self._samp_rows(slots_real, nb)
-        with self._timed("prefill") as tm:
+        # one program call, one ring span per member slot (below)
+        with self._span("prefill") as tm:
             first, self.states = self._prefill(
                 self.params, jnp.asarray(toks), jnp.asarray(lens),
                 jnp.asarray(slot_ids), self.states, bt, *samp)
@@ -988,12 +1012,17 @@ class ServeEngine:
                               ("rows", n)))
             st.record_ttft(now - req.t_submit)
             st.bucket_counts[bucket] = st.bucket_counts.get(bucket, 0) + 1
-            if self.kv is not None:
-                self.kv.publish(slot, req.prompt)
+            self._publish(slot, req.prompt)
             if len(req.generated) >= req.max_new_tokens or tok == req.eos_id:
                 self._finish(slot, now)
             elif self.role == "prefill":
                 self._stage_ready(slot, now)
+
+    def _publish(self, slot: int, tokens: list[int]) -> None:
+        """Publish a freshly prefilled prompt's blocks for prefix hits."""
+        if self.kv is not None:
+            with self._span("kv_publish", self._trk_engine):
+                self.kv.publish(slot, tokens)
 
     def _advance_chunk(self, slot: int) -> None:
         req = self.requests[slot]
@@ -1009,7 +1038,8 @@ class ServeEngine:
         # no-sync fast path on intermediate chunks recorded dispatch time as
         # prefill time (the async-dispatch under-reporting bug) and hid the
         # chunk's real cost from the per-tick timeline
-        with self._timed("prefill_chunk") as tm:
+        with self._span("prefill_chunk", self._slot_track(slot),
+                        (("rid", req.rid), ("offset", off), ("n", n))) as tm:
             tok, self.states = self._chunk(
                 self.params, jnp.asarray(toks), jnp.asarray(off, jnp.int32),
                 jnp.asarray(n, jnp.int32), jnp.asarray(slot, jnp.int32),
@@ -1023,9 +1053,6 @@ class ServeEngine:
         self.programs.observe("chunk", tm.dur, phase="prefill",
                               program="_chunk")
         st.metrics.counter("prefill_waste_tokens", "tokens").inc(c - n)
-        self.tracer.span("prefill_chunk", self._slot_track(slot),
-                         tm.t0, tm.t1,
-                         (("rid", req.rid), ("offset", off), ("n", n)))
         if off + n < len(req.prompt):
             self._prefilling[slot] = off + n
             return
@@ -1039,8 +1066,7 @@ class ServeEngine:
         st.prefills_chunked += 1
         st.prefill_prompt_tokens += len(req.prompt)
         st.record_ttft(now - req.t_submit)
-        if self.kv is not None:
-            self.kv.publish(slot, req.prompt)
+        self._publish(slot, req.prompt)
         if len(req.generated) >= req.max_new_tokens or tok == req.eos_id:
             self._finish(slot, now)
         elif self.role == "prefill":
@@ -1061,7 +1087,8 @@ class ServeEngine:
             # generated token was sampled but never fed back through decode,
             # so its KV was never written — publish only the written prefix
             # or a block-aligned sequence would share a garbage position.
-            self.kv.finish(slot, req.prompt + req.generated[:-1])
+            with self._span("kv_release", self._trk_engine):
+                self.kv.finish(slot, req.prompt + req.generated[:-1])
         self.stats.requests_completed += 1
         self.stats.tokens_generated += len(req.generated)
 
@@ -1082,7 +1109,8 @@ class ServeEngine:
         req = self.requests[slot]
         trow = jnp.asarray(np.asarray(self.kv.table[slot], np.int32)) \
             if self.kv is not None else None
-        with self._timed("handoff_export") as tm:
+        with self._span("handoff_export", self._slot_track(slot),
+                        (("rid", req.rid),)) as tm:
             out = self._export(self.states, jnp.asarray(slot, jnp.int32),
                                trow)
             tm.sync(out)
@@ -1091,8 +1119,6 @@ class ServeEngine:
         st.handoff_time_s += tm.dur
         self.programs.observe("export", tm.dur, phase="handoff",
                               program="_export")
-        self.tracer.span("handoff_export", self._slot_track(slot),
-                         tm.t0, tm.t1, (("rid", req.rid),))
         return out
 
     def release_handoff(self, slot: int) -> None:
@@ -1104,7 +1130,8 @@ class ServeEngine:
                         (("rid", req.rid), ("handoff", 1)))
         self.requests[slot] = None
         if self.kv is not None:
-            self.kv.release(slot)
+            with self._span("kv_release", self._trk_engine):
+                self.kv.release(slot)
         self._sync_kv_stats()
 
     def stage_in(self, suitcase):
@@ -1131,12 +1158,15 @@ class ServeEngine:
             self.stats.handoff_stalls += 1
             return None
         slot = free[0]
-        if self.kv is not None and not self.kv.adopt(slot, n_tokens):
-            self.stats.handoff_stalls += 1
-            return None
+        if self.kv is not None:
+            with self._span("kv_admit", self._trk_engine):
+                adopted = self.kv.adopt(slot, n_tokens)
+            if not adopted:
+                self.stats.handoff_stalls += 1
+                return None
         trow = jnp.asarray(np.asarray(self.kv.table[slot], np.int32)) \
             if self.kv is not None else None
-        with self._timed("handoff_import") as tm:
+        with self._span("handoff_import") as tm:
             self.states = self._import(self.states, suitcase,
                                        jnp.asarray(slot, jnp.int32), trow)
             tm.sync(self.states)
@@ -1178,7 +1208,7 @@ class ServeEngine:
         # optionally compiled memory watermarks) immediately before its
         # warmup call — same args, so the registered shape IS the warmed one
         reg, mem = self.programs, self._program_memory
-        with self._timed("warmup") as tm:
+        with self._span("warmup", self._trk_engine) as tm:
             if self.role != "decode":
                 for b in self.buckets:
                     for nb in self.batch_buckets:
@@ -1223,7 +1253,6 @@ class ServeEngine:
                 self.slots, self.max_len, **self._state_kw,
                 shardings=self._state_shardings)
             tm.sync(self.states)
-        self.tracer.span("warmup", self._trk_engine, tm.t0, tm.t1)
         if self.kv is not None:
             # the device pool was just re-zeroed: drop every cached prefix
             # that described its old contents
@@ -1277,18 +1306,21 @@ class ServeEngine:
         advance every decoding slot by one lockstep decode step (dead and
         mid-prefill slots are frozen by the ``active`` mask).  Paged engines
         extend each slot's block table before the write and stall (freeze) a
-        slot for the tick when the pool has no block for it."""
-        t_tick = self.tracer.now()
-        if self.role != "decode":
-            for slot in list(self._prefilling):
-                self._advance_chunk(slot)
-            self._admit(self.max_prefill_per_step)
-        busy = [i for i, r in enumerate(self.requests) if r is not None]
-        # a prefill-role engine never decodes: ready slots wait for export
-        active = [] if self.role == "prefill" \
-            else [i for i in busy if i not in self._prefilling]
-        if self.kv is not None and active:
-            ok = []
+        slot for the tick when the pool has no block for it.
+
+        Every stretch of the tick is a span on the engine track: ``tick``
+        around it all, the scheduler's ``schedule`` / ``decode_prep`` /
+        ``retire`` / ``tick_stats``, the KV manager's ``kv_admit`` /
+        ``kv_extend`` / ``kv_publish`` / ``kv_release`` inside them, and the
+        program calls."""
+        with self._span("tick", self._trk_engine) as tick:
+            tick.args = self._tick(tick.t0)
+
+    def _extend(self, active: list[int]) -> list[int]:
+        """Extend each active slot's block table over this tick's write;
+        returns the slots that got their block (the rest stall a tick)."""
+        ok = []
+        with self._span("kv_extend", self._trk_engine):
             for i in active:
                 # the write this tick lands at position[i]: the table must
                 # cover position[i] + 1 tokens
@@ -1299,77 +1331,95 @@ class ServeEngine:
                     self.tracer.instant(
                         "stall", self._slot_track(i), self.tracer.now(),
                         (("rid", self.requests[i].rid),))
-            if not ok and not self._prefilling:
-                # nothing can decode and nothing mid-prefill will retire:
-                # no block can ever free — fail loudly instead of spinning
-                raise RuntimeError(
-                    f"KV pool exhausted: {self.kv.in_use} of "
-                    f"{self.kv.pool.num_blocks} blocks referenced, every "
-                    f"active slot stalled and nothing can retire — size the "
-                    f"pool for at least one request's worst case "
-                    f"(kv_blocks >= max_len / kv_block_size)")
-            active = ok
-        self.stats.ticks += 1
-        self.stats.occupancy_sum += len(busy) / self.slots
-        if not active:
+        if not ok and not self._prefilling:
+            # nothing can decode and nothing mid-prefill will retire: no
+            # block can ever free — fail loudly instead of spinning
+            raise RuntimeError(
+                f"KV pool exhausted: {self.kv.in_use} of "
+                f"{self.kv.pool.num_blocks} blocks referenced, every active "
+                f"slot stalled and nothing can retire — size the pool for "
+                f"at least one request's worst case "
+                f"(kv_blocks >= max_len / kv_block_size)")
+        return ok
+
+    def _tick(self, t_tick: float) -> tuple:
+        """The body of :meth:`step`; returns the ``tick`` span's args."""
+        trk, st = self._trk_engine, self.stats
+        chunks0, admitted, stalled = st.prefill_chunks, 0, 0
+        with self._span("schedule", trk):
+            if self.role != "decode":
+                for slot in list(self._prefilling):
+                    self._advance_chunk(slot)
+                admitted = self._admit(self.max_prefill_per_step)
+            busy = [i for i, r in enumerate(self.requests) if r is not None]
+            # a prefill-role engine never decodes: ready slots wait for export
+            active = [] if self.role == "prefill" \
+                else [i for i in busy if i not in self._prefilling]
+        tm = None
+        if active:
+            with self._span("decode_prep", trk):
+                if self.kv is not None:
+                    ok = self._extend(active)
+                    stalled, active = len(active) - len(ok), ok
+                toks = np.zeros((self.slots, 1), np.int32)
+                mask = np.zeros((self.slots,), bool)
+                for i in active:
+                    mask[i] = True
+                    req = self.requests[i]
+                    toks[i, 0] = req.generated[-1] if req.generated \
+                        else req.prompt[-1]
+                bt, samp = self._decode_args() if active else (None, None)
+        if active:
+            with self._span("decode", trk, (("active", len(active)),)) as tm:
+                nxt, self.states = self._decode(
+                    self.params, jnp.asarray(toks), self.states,
+                    jnp.asarray(self.positions), self.memory,
+                    jnp.asarray(mask), bt, *samp)
+                nxt = tm.sync(nxt)           # device sync BEFORE the stamp
+            with self._span("retire", trk):
+                nxt = np.asarray(nxt, np.int32)
+                for i in active:
+                    req = self.requests[i]
+                    self.positions[i] += 1
+                    req.generated.append(int(nxt[i]))
+                    if (len(req.generated) >= req.max_new_tokens
+                            or int(nxt[i]) == req.eos_id
+                            or self.positions[i] >= self.max_len - 1):
+                        self._finish(i, tm.t1)
+        with self._span("tick_stats", trk):
+            st.ticks += 1
+            st.occupancy_sum += len(busy) / self.slots
+            if tm is not None:
+                st.decode_steps += 1
+                st.decode_time_s += tm.dur
+                self.programs.observe("decode", tm.dur, phase="decode",
+                                      program="_decode")
+                st.metrics.histogram("decode_tick_s").record(tm.dur)
+                st.metrics.histogram(
+                    "tokens_per_tick", base=1.0, unit="tokens").record(
+                        len(active))
             self._sync_compile_stats()
             self._sync_kv_stats()
-            self.stats.kv_occupancy_sum += (
+            st.kv_occupancy_sum += (
                 self.kv.in_use / self.kv.pool.num_blocks
                 if self.kv is not None else 0.0)
-            now = self.tracer.now()
-            self._tick_counters(now, len(busy))
-            self.stats.wall_time_s += now - t_tick
-            return
-        toks = np.zeros((self.slots, 1), np.int32)
-        mask = np.zeros((self.slots,), bool)
-        for i in active:
-            mask[i] = True
-            toks[i, 0] = self.requests[i].generated[-1] \
-                if self.requests[i].generated else self.requests[i].prompt[-1]
-        bt, samp = self._decode_args()
-        with self._timed("decode") as tm:
-            nxt, self.states = self._decode(
-                self.params, jnp.asarray(toks), self.states,
-                jnp.asarray(self.positions), self.memory, jnp.asarray(mask),
-                bt, *samp)
-            nxt = tm.sync(nxt)               # device sync BEFORE the stamp
-        nxt = np.asarray(nxt, np.int32)
-        now = tm.t1
-        self.stats.decode_steps += 1
-        self.stats.decode_time_s += tm.dur
-        self.programs.observe("decode", tm.dur, phase="decode",
-                              program="_decode")
-        self.stats.metrics.histogram("decode_tick_s").record(tm.dur)
-        self.stats.metrics.histogram(
-            "tokens_per_tick", base=1.0, unit="tokens").record(len(active))
-        self.tracer.span("decode", self._trk_engine, tm.t0, tm.t1,
-                         (("active", len(active)),))
-        for i in active:
-            req = self.requests[i]
-            self.positions[i] += 1
-            req.generated.append(int(nxt[i]))
-            if (len(req.generated) >= req.max_new_tokens
-                    or int(nxt[i]) == req.eos_id
-                    or self.positions[i] >= self.max_len - 1):
-                self._finish(i, now)
-        self._sync_compile_stats()
-        self._sync_kv_stats()
-        self.stats.kv_occupancy_sum += (
-            self.kv.in_use / self.kv.pool.num_blocks
-            if self.kv is not None else 0.0)
-        end = self.tracer.now()
-        # time-between-tokens as a running slot experiences it: the whole
-        # tick's wall, chunk-prefill and admission interference included —
-        # on a dedicated decode submesh the tick carries only the decode
-        # program, which is exactly the latency win the --disagg gate
-        # measures (interleaved p99 carries chunk ticks; disagg p99 doesn't)
-        self.stats.metrics.histogram("decode_tbt_s").record(end - t_tick)
-        self._tick_counters(end, len([r for r in self.requests
-                                      if r is not None]))
-        # wall time accumulates per tick so tokens_per_s stays meaningful for
-        # callers driving submit()+step() directly instead of run()
-        self.stats.wall_time_s += end - t_tick
+            end = self.tracer.now()
+            if tm is not None:
+                # time-between-tokens as a running slot experiences it: the
+                # whole tick's wall, chunk-prefill and admission interference
+                # included — on a dedicated decode submesh the tick carries
+                # only the decode program, which is exactly the latency win
+                # the --disagg gate measures (interleaved p99 carries chunk
+                # ticks; disagg p99 doesn't)
+                st.metrics.histogram("decode_tbt_s").record(end - t_tick)
+            self._tick_counters(end, sum(r is not None
+                                         for r in self.requests))
+            # wall time accumulates per tick so tokens_per_s stays
+            # meaningful for callers driving submit()+step() directly
+            st.wall_time_s += end - t_tick
+        return (("active", len(active)), ("admitted", admitted),
+                ("chunks", st.prefill_chunks - chunks0),
+                ("stalled", stalled))
 
     def run(self, requests: list[Request], max_steps: int = 10_000,
             on_truncate: str = "warn") -> list[Request]:

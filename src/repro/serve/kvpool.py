@@ -67,6 +67,10 @@ class RadixPrefixCache:
     finishes).  A published block may still be referenced by running slots;
     the pool's refcounts decide when it becomes evictable.  Eviction removes
     leaf nodes only, so every cached block's prefix path stays intact.
+
+    Work counters: ``evict_scanned`` (nodes :meth:`evict_lru` looked at)
+    and ``reclaim_walked`` (nodes :meth:`reclaimable` walked), each added
+    by its loop's length per call.
     """
 
     def __init__(self, block_size: int):
@@ -74,6 +78,8 @@ class RadixPrefixCache:
         self.root = _RadixNode((), -1, None)
         self.by_block: dict[int, _RadixNode] = {}
         self._clock = 0
+        self.evict_scanned = 0
+        self.reclaim_walked = 0
 
     def __len__(self) -> int:
         return len(self.by_block)
@@ -146,6 +152,8 @@ class RadixPrefixCache:
         actually free: a node counts only if its ENTIRE subtree is
         unreferenced — an unreferenced ancestor of a block some slot still
         maps can never become a leaf while that reference lives."""
+        self.reclaim_walked += len(self.by_block)
+
         def walk(node):
             clean = True
             cnt = 0
@@ -164,6 +172,7 @@ class RadixPrefixCache:
         """Remove and return the least-recently-used *leaf* block for which
         ``evictable(block_id)`` holds (i.e. refcount 0).  None if nothing
         qualifies."""
+        self.evict_scanned += len(self.by_block)
         best: _RadixNode | None = None
         for node in self.by_block.values():
             if node.children or not evictable(node.block):
@@ -348,6 +357,18 @@ class PagedKVManager:
         return self.pool.blocks_evicted
 
     @property
+    def evict_scanned(self) -> int:
+        """Radix nodes LRU eviction has looked at (its cost, not its
+        result: every cached node per block allocated from an empty free
+        list)."""
+        return self.tree.evict_scanned
+
+    @property
+    def reclaim_walked(self) -> int:
+        """Radix nodes the admission supply check has walked."""
+        return self.tree.reclaim_walked
+
+    @property
     def shards(self) -> int:
         return self.pool.shards
 
@@ -365,6 +386,7 @@ class PagedKVManager:
     def reset_stats(self) -> None:
         self.stats = KVPoolStats()
         self.pool.blocks_evicted = 0
+        self.tree.evict_scanned = self.tree.reclaim_walked = 0
         self.pool.peak_in_use = self.pool.in_use
         self.pool.peak_by_shard = list(self.pool.in_use_by_shard)
 
@@ -378,7 +400,10 @@ class PagedKVManager:
         self.pool = KVBlockPool(self.pool.num_blocks, self.block_size,
                                 self.pool.shards)
         self.pool.blocks_evicted = evicted
+        old = self.tree
         self.tree = RadixPrefixCache(self.block_size)
+        self.tree.evict_scanned = old.evict_scanned
+        self.tree.reclaim_walked = old.reclaim_walked
         self.table = [[self.sentinel] * self.blocks_per_slot
                       for _ in range(self.slots)]
         self.version += 1

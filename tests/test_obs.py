@@ -19,6 +19,7 @@ from repro.obs import (LEDGER_SCHEMA_VERSION, OBS_SCHEMA_VERSION,
 from repro.obs import ledger as ledger_mod
 from repro.obs.drift import (PHASES, drift_report, geomean, plan_predictions,
                              residual_factor)
+from repro.obs.trace import Section
 from repro.serve.engine import Request, ServeEngine
 
 ARCHS = ("qwen3-0.6b", "recurrentgemma-2b", "falcon-mamba-7b")
@@ -656,6 +657,216 @@ def test_engine_memory_gauges_and_device_memory_track(tmp_path):
     assert mem, "no device_memory_bytes counter track in the trace"
     assert {"slot_state", "kv_pool"} <= set(mem[0]["args"])
     assert "programs" in doc["otherData"]
+
+
+# ------------------------------------------------------- one span call
+def test_tracer_section_is_one_span_in_both_sinks():
+    """``Tracer.section`` stamps on the tracer's clock, syncs like Timed,
+    appends one X event at exit (args may be set inside the block), and
+    emits nothing to the ring without a track or with the tracer off."""
+    ticks = iter([1.0, 1.5, 2.0, 2.25, 3.0, 3.5])
+    tr = Tracer(clock=lambda: next(ticks))      # epoch reads 1.0
+    with tr.section("tick", 4, (("a", 1),)) as sec:
+        out = sec.sync(_jit_incr(np.arange(3.0)))
+        sec.args = (("a", 2),)
+    assert isinstance(sec, Timed) and sec.synced
+    assert (sec.t0, sec.t1, sec.dur) == (1.5, 2.0, 0.5)
+    np.testing.assert_array_equal(np.asarray(out), np.arange(3.0) + 1)
+    assert tr.events() == [("X", "tick", 4, 1.5, 0.5, (("a", 2),))]
+    with tr.section("prefill", None) as sec:    # caller emits its own
+        pass
+    assert sec.dur == 0.75 and len(tr) == 1
+    off = Tracer(enabled=False)
+    with off.section("tick", 0):
+        pass
+    assert len(off) == 0
+
+
+def test_timed_opens_no_profiler_annotation():
+    """The annotation path lives in the one span call only."""
+    assert not hasattr(Timed("x"), "profile")
+    with pytest.raises(TypeError):
+        Timed("x", profile=True)
+
+
+#: the engine's host phases of a tick, and where each KV-manager entry
+#: point and program call must run
+SCHEDULER_PHASES = ("schedule", "decode_prep", "retire", "tick_stats")
+KV_SPANS = {"admit": "kv_admit", "extend": "kv_extend",
+            "publish": "kv_publish", "finish": "kv_release"}
+PROGRAM_SPANS = {"_decode": "decode", "_prefill": "prefill",
+                 "_chunk": "prefill_chunk", "_copy": "kv_copy"}
+
+
+class _StackTracer(Tracer):
+    """A tracer that keeps the names of the sections open right now."""
+
+    def __init__(self):
+        super().__init__()
+        self.open: list[str] = []
+
+    def section(self, name, tid, args=(), *, profile=False):
+        return _OpenSection(self, name, tid, args, profile)
+
+
+class _OpenSection(Section):
+    __slots__ = ()
+
+    def __enter__(self):
+        self.tracer.open.append(self.name)
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        out = super().__exit__(*exc)
+        assert self.tracer.open.pop() == self.name
+        return out
+
+
+def _shared_prefix_requests(cfg, n=4):
+    """Prompts that share 12 tokens: after the first publishes its two full
+    8-token blocks, the others hit one block and copy the next (COW)."""
+    rng = np.random.RandomState(3)
+    head = rng.randint(1, cfg.vocab_size, 12).tolist()
+    return [Request(rid=i, prompt=head + rng.randint(
+                1, cfg.vocab_size, 5 + 7 * i).tolist(), max_new_tokens=4)
+            for i in range(n)]
+
+
+def _paged_engine(tracer=None, slots=2):
+    cfg, model, params = _tiny_model()
+    engine = ServeEngine(model, params, slots=slots, max_len=64,
+                         buckets=(8, 16, 32), prefill_chunk=16,
+                         kv_block_size=8, kv_blocks=16, tracer=tracer)
+    return cfg, engine
+
+
+def test_engine_calls_run_inside_their_spans():
+    """Every KV-manager entry point and program call on the tick path runs
+    inside its own span, nested in ``tick`` and a scheduler phase."""
+    tracer = _StackTracer()
+    cfg, engine = _paged_engine(tracer)
+    engine.warmup()
+    seen: dict[str, list] = {}
+
+    def spy(obj, attr, key):
+        fn = getattr(obj, attr)
+
+        def wrapped(*a, **k):
+            seen.setdefault(key, []).append(list(tracer.open))
+            return fn(*a, **k)
+        if hasattr(fn, "_cache_size"):   # the compile-count gate reads it
+            wrapped._cache_size = fn._cache_size
+        setattr(obj, attr, wrapped)
+
+    for attr in KV_SPANS:
+        spy(engine.kv, attr, attr)
+    for attr in PROGRAM_SPANS:
+        spy(engine, attr, attr)
+    engine.run(_shared_prefix_requests(cfg))
+    assert set(seen) == set(KV_SPANS) | set(PROGRAM_SPANS)
+    want = {**KV_SPANS, **PROGRAM_SPANS}
+    for key, stacks in seen.items():
+        for stack in stacks:
+            # ``finish`` publishes the finished sequence itself
+            assert stack[-1] == want[key] or (
+                key == "publish" and stack[-1] == "kv_release"), (key, stack)
+            # the decode program is a phase of the tick itself
+            assert stack[0] == "tick" and (stack[1] in SCHEDULER_PHASES or (
+                key == "_decode" and len(stack) == 2)), (key, stack)
+    assert any(st[-1] == "kv_publish" for st in seen["publish"])
+    # the whole tick is covered: every span is tick or inside one
+    ticks = [e for e in tracer.events() if e[1] == "tick"]
+    assert len(ticks) == engine.stats.ticks
+    assert sum(dict(e[5])["admitted"] for e in ticks) == 4
+    assert sum(dict(e[5])["chunks"] for e in ticks) \
+        == engine.stats.prefill_chunks
+    for e in tracer.events():
+        if e[0] == "X" and e[1] in SCHEDULER_PHASES + tuple(KV_SPANS.values()):
+            assert any(t[3] <= e[3] and e[3] + e[4] <= t[3] + t[4]
+                       for t in ticks), e
+
+
+def test_profiled_ticks_carry_host_phases_as_annotations(tmp_path):
+    """With ``profile`` set after construction, a CPU profile holds the
+    engine's phases as host annotations nested under ``tick``, and each
+    tick's annotation carries the ring's stamp of it (``t_us``)."""
+    from jax.profiler import ProfileData
+    cfg, engine = _paged_engine()
+    engine.warmup()
+    engine.submit(_shared_prefix_requests(cfg, n=1)[0])
+    engine.step()                      # unprofiled: no annotation objects
+    engine.profile = True
+    jax.profiler.start_trace(str(tmp_path))
+    for r in _shared_prefix_requests(cfg)[1:]:
+        engine.submit(r)
+    for _ in range(4):
+        engine.step()
+    jax.profiler.stop_trace()
+    pb = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    host: dict[str, list] = {}
+    for plane in ProfileData.from_file(str(pb)).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                host.setdefault(ev.name, []).append(
+                    (ev.start_ns, ev.start_ns + ev.duration_ns,
+                     dict(ev.stats) if ev.name == "tick" else {}))
+    assert len(host["tick"]) == 4
+    for name in SCHEDULER_PHASES + ("kv_admit", "kv_extend", "kv_publish",
+                                    "decode", "prefill_chunk"):
+        assert name in host, name
+        for a, b, _ in host[name]:
+            assert any(ta <= a and b <= tb for ta, tb, _ in host["tick"]), \
+                name
+    ring = [e[3] for e in engine.tracer.events() if e[1] == "tick"][-4:]
+    stamps = sorted(st["t_us"] for _, _, st in host["tick"])
+    assert stamps == [round(engine.tracer._us(t)) for t in ring]
+
+
+def test_ring_events_per_tick_stay_bounded():
+    """The ring takes a few events per tick plus a few per request and per
+    slot, however long the engine runs: far below the 2^19-event ring a
+    benchmark run keeps whole."""
+    cfg, engine = _paged_engine(slots=4)
+    engine.warmup()
+    engine.tracer.clear()
+    rng = np.random.RandomState(0)
+    reqs = [Request(rid=i, prompt=rng.randint(1, cfg.vocab_size,
+                                               int(rng.randint(3, 40))
+                                               ).tolist(),
+                    max_new_tokens=int(rng.randint(2, 9)))
+            for i in range(24)]
+    engine.run(reqs)
+    evs = engine.tracer.events()
+    ticks = [(e[3], e[3] + e[4]) for e in evs if e[1] == "tick"]
+    assert len(ticks) == engine.stats.ticks and engine.tracer.dropped == 0
+    per_tick = [sum(1 for e in evs if a <= e[3] <= b) for a, b in ticks]
+    # 7 spans + 5 counters a tick; per admitted slot at most 9 events
+    # (B, E, kv_admit, cow_copy, kv_copy, prefill or a chunk, kv_publish,
+    # kv_release, stall)
+    assert max(per_tick) <= 12 + 9 * engine.slots, max(per_tick)
+    # over the run: the same, with submit, and one event per chunk call
+    chunks = sum(1 for e in evs if e[1] == "prefill_chunk")
+    stalls = sum(1 for e in evs if e[1] == "stall")
+    assert len(evs) <= 12 * len(ticks) + 9 * len(reqs) + chunks + stalls
+
+
+def test_engine_kv_work_counters_in_summary_and_counter_track():
+    cfg, engine = _paged_engine()
+    engine.run(_shared_prefix_requests(cfg))
+    kv = engine.stats.summary()["kv"]
+    assert kv["reclaim_walked"] == engine.kv.reclaim_walked > 0
+    assert kv["evict_scanned"] == engine.kv.evict_scanned
+    samples = [dict(e[5]) for e in engine.tracer.events()
+               if e[0] == "C" and e[1] == "kv_blocks"]
+    assert set(samples[0]) == {"in_use", "cached", "evict_scanned",
+                               "reclaim_walked"}
+    # per-tick work: the samples add up to the totals
+    assert sum(s["reclaim_walked"] for s in samples) == kv["reclaim_walked"]
+    assert sum(s["evict_scanned"] for s in samples) == kv["evict_scanned"]
+    engine.reset_stats()
+    assert engine.stats.summary()["kv"]["reclaim_walked"] == 0
 
 
 # -------------------------------------------------------------------- ledger

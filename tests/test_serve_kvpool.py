@@ -82,6 +82,35 @@ def test_pool_lru_eviction_prefers_oldest_cached():
     del b2
 
 
+def test_eviction_and_supply_walks_are_counted():
+    """The KV manager's own work: each ``evict_lru`` call adds every cached
+    node it looks at, each ``reclaimable`` walk every node of the tree;
+    ``clear`` keeps the counts and ``reset_stats`` zeroes them."""
+    bs = 2
+    mgr = PagedKVManager(slots=2, max_len=8, block_size=bs, num_blocks=6)
+    # fill the pool with a published, retired 4-block sequence and a
+    # 2-block one: 6 cached nodes, no free block
+    for slot, toks in ((0, [1, 2, 3, 4, 5, 6, 7]), (1, [9, 8, 7])):
+        assert mgr.admit(slot, toks) is not None
+        assert mgr.extend(slot, len(toks) + 1)
+        mgr.finish(slot, toks + [0])
+    assert len(mgr.tree) == 6 and not mgr.pool.free
+    assert mgr.evict_scanned == 0
+    walked = mgr.reclaim_walked                # the admissions' supply checks
+    assert walked == 0 + 4                     # an empty tree, then 4 nodes
+    # a cold 3-token prompt needs 2 blocks: one supply walk over 6 nodes,
+    # then two evictions, scanning 6 and then 5 nodes
+    assert mgr.admit(0, [5, 5, 5]) is not None
+    assert mgr.reclaim_walked == walked + 6
+    assert mgr.blocks_evicted == 2
+    assert mgr.evict_scanned == 6 + 5
+    mgr.release(0)
+    mgr.clear()
+    assert (mgr.evict_scanned, mgr.reclaim_walked) == (11, walked + 6)
+    mgr.reset_stats()
+    assert (mgr.evict_scanned, mgr.reclaim_walked) == (0, 0)
+
+
 def test_radix_match_full_and_partial_blocks():
     bs = 4
     tree = RadixPrefixCache(bs)

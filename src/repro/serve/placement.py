@@ -312,6 +312,17 @@ class ExecutionOracle:
                 f"backend {backend!r} lowers via XLA reference path"
         off = {kp: why for kp, why in _MESH_XLA.items()
                if self.mesh_axes and kp[0] in knobs}
+        if "attn_impl" in knobs and {"attn", "dec"} & set(kinds):
+            import jax.numpy as jnp
+
+            from ..kernels.paged_attention.kernel import lowers
+            cfg = self.cfg
+            if not lowers(cfg.num_kv_heads, cfg.head_dim,
+                          jnp.dtype(cfg.compute_dtype).itemsize):
+                off[("attn_impl", "decode")] = (
+                    f"decode attention stays on the XLA paged gather: the "
+                    f"paged kernel cannot copy pages of {cfg.num_kv_heads} "
+                    f"kv heads x {cfg.head_dim} out of the pool")
         phases = {ph: [k for k in knobs if (k, ph) not in off]
                   for ph in ("prefill", "decode")}
         variants = sorted({_PALLAS_VARIANTS[k][ph]

@@ -13,6 +13,7 @@ from repro.kernels.flash_attention import flash_attention, flash_attention_ref
 from repro.kernels.jacquard_gemv import jacquard_gemv, jacquard_gemv_ref
 from repro.kernels.paged_attention import (paged_decode_attention,
                                            paged_decode_attention_ref)
+from repro.kernels.paged_attention.kernel import pages_per_group
 from repro.kernels.pascal_matmul import pascal_matmul, pascal_matmul_ref
 from repro.kernels.pavlov_lstm import pavlov_lstm, pavlov_lstm_ref
 from repro.kernels.pavlov_rglru import pavlov_rglru, pavlov_rglru_ref
@@ -229,16 +230,41 @@ def test_flash_kernel_property(seed):
 
 
 # ----------------------------------------------------------- paged_attention
+def _owned_tables(rng, n, nb, bs, lengths):
+    """Each slot owns distinct physical blocks for exactly its live positions
+    0..lengths[b]; every table entry past them is the sentinel ``n``."""
+    perm = rng.permutation(n)
+    table = np.full((len(lengths), nb), n, np.int32)
+    off = 0
+    for b, length in enumerate(lengths):
+        owned = length // bs + 1
+        table[b, :owned] = perm[off:off + owned]
+        off += owned
+    assert off <= n
+    return table
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("h,kvh,hd,n,bs,nb", [
-    (4, 4, 16, 8, 8, 4),
-    (4, 2, 16, 10, 8, 4),               # GQA
-    (8, 1, 8, 6, 16, 2),                # MQA
-    (2, 2, 32, 12, 4, 8),               # many small blocks
+@pytest.mark.parametrize("h,kvh,hd,n,bs,nb,live", [
+    pytest.param(4, 4, 16, 8, 8, 4, None, id="4-4-16-8-8-4"),
+    pytest.param(4, 2, 16, 10, 8, 4, None, id="4-2-16-10-8-4"),     # GQA
+    pytest.param(8, 1, 8, 6, 16, 2, None, id="8-1-8-6-16-2"),       # MQA
+    # many small blocks
+    pytest.param(2, 2, 32, 12, 4, 8, None, id="2-2-32-12-4-8"),
+    # P = 3 pages of 8: nb 7 leaves a last group of one page
+    pytest.param(4, 2, 16, 24, 8, 7, (55, 20, 50), id="nb-not-multiple-of-P"),
+    # k*P*bs - 1 ends a group, k*P*bs opens one
+    pytest.param(4, 2, 16, 24, 8, 7, (23, 24, 47), id="group-edges"),
+    # empty slots (length 0) around a one-block prefix
+    pytest.param(4, 2, 16, 24, 8, 7, (0, 5, 0), id="empty-and-short"),
+    # qwen3-0.6b's heads and blocks, P = 3
+    pytest.param(16, 8, 128, 16, 16, 6, (47, 48, 95), id="qwen3-heads"),
 ])
-def test_paged_decode_kernel(dtype, h, kvh, hd, n, bs, nb):
+def test_paged_decode_kernel(dtype, h, kvh, hd, n, bs, nb, live):
     """Block-table gather kernel vs the pure-jnp paged reference: scattered
-    pools, ragged per-slot lengths, sentinel (unallocated) table entries."""
+    pools, ragged per-slot lengths, sentinel (unallocated) table entries.
+    ``live`` fixes the lengths, each slot owning exactly its live blocks;
+    None draws them."""
     B = 3
     ks = jax.random.split(jax.random.PRNGKey(11), 5)
     q = _rand(ks[0], B, 1, h, hd, dtype=dtype)
@@ -247,17 +273,21 @@ def test_paged_decode_kernel(dtype, h, kvh, hd, n, bs, nb):
     kp = _rand(ks[3], n, bs, kvh, hd, dtype=dtype)
     vp = _rand(ks[4], n, bs, kvh, hd, dtype=dtype)
     rng = np.random.RandomState(7)
-    # distinct physical blocks per slot, rest sentinel (= n, "no block")
-    perm = rng.permutation(n)
-    table = np.full((B, nb), n, np.int32)
-    lengths = np.zeros((B,), np.int32)
-    off = 0
-    for b in range(B):
-        owned = rng.randint(1, nb + 1)
-        owned = min(owned, n - off)
-        table[b, :owned] = perm[off:off + owned]
-        off += owned
-        lengths[b] = rng.randint(0, owned * bs)   # write pos inside coverage
+    if live is not None:
+        lengths = np.asarray(live, np.int32)
+        table = _owned_tables(rng, n, nb, bs, lengths)
+    else:
+        # distinct physical blocks per slot, rest sentinel (= n, "no block")
+        perm = rng.permutation(n)
+        table = np.full((B, nb), n, np.int32)
+        lengths = np.zeros((B,), np.int32)
+        off = 0
+        for b in range(B):
+            owned = rng.randint(1, nb + 1)
+            owned = min(owned, n - off)
+            table[b, :owned] = perm[off:off + owned]
+            off += owned
+            lengths[b] = rng.randint(0, owned * bs)   # write pos in coverage
     out, k2, v2 = paged_decode_attention(q, nk, nv, kp, vp,
                                          jnp.asarray(table),
                                          jnp.asarray(lengths))
@@ -270,14 +300,18 @@ def test_paged_decode_kernel(dtype, h, kvh, hd, n, bs, nb):
                                np.asarray(outr, np.float32), **_tol(dtype))
 
 
-@pytest.mark.parametrize("seed", range(8))
+@pytest.mark.parametrize("seed", range(16))
 def test_paged_decode_kernel_property(seed):
+    """Seeds 8-15 draw odd table widths (a short last page group) and
+    lengths on the groups' edges, 0 included."""
     rng = random.Random(3000 + seed)
     h, kvh = rng.choice([(4, 4), (4, 2), (8, 1)])
     hd = rng.choice([8, 16])
     bs = rng.choice([4, 8])
     nb = rng.choice([2, 4])
     B = rng.choice([1, 2, 4])
+    if seed >= 8:
+        nb = rng.choice([3, 5, 7])
     n = B * nb + rng.choice([0, 3])
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     q = _rand(ks[0], B, 1, h, hd)
@@ -288,6 +322,11 @@ def test_paged_decode_kernel_property(seed):
     nrng = np.random.RandomState(seed)
     table = nrng.permutation(n)[:B * nb].reshape(B, nb).astype(np.int32)
     lengths = nrng.randint(0, nb * bs, size=B).astype(np.int32)
+    if seed >= 8:
+        edge = pages_per_group(bs, nb) * bs
+        edges = [0, edge - 1, edge, 2 * edge - 1, 2 * edge]
+        lengths = np.minimum(nrng.choice(edges, size=B),
+                             nb * bs - 1).astype(np.int32)
     out, k2, v2 = paged_decode_attention(q, nk, nv, kp, vp,
                                          jnp.asarray(table),
                                          jnp.asarray(lengths))
@@ -295,6 +334,33 @@ def test_paged_decode_kernel_property(seed):
                                                 jnp.asarray(table),
                                                 jnp.asarray(lengths))
     np.testing.assert_array_equal(np.asarray(k2), np.asarray(k2r))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(outr),
+                               atol=1e-4, rtol=1e-4)
+
+
+def test_paged_decode_kernel_reads_no_dead_page():
+    """Every pool block outside the slots' live ranges is NaN, the block the
+    sentinels clamp to (n - 1) among them: the kernel never fetches one, so
+    its output is finite and equals the reference over the clean pool."""
+    h, kvh, hd, n, bs, nb = 4, 2, 16, 24, 8, 7          # P = 3 pages
+    lengths = np.asarray([0, 23, 50, 5], np.int32)
+    B = len(lengths)
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    q = _rand(ks[0], B, 1, h, hd)
+    nk = _rand(ks[1], B, 1, kvh, hd)
+    nv = _rand(ks[2], B, 1, kvh, hd)
+    kp = _rand(ks[3], n, bs, kvh, hd)
+    vp = _rand(ks[4], n, bs, kvh, hd)
+    table = _owned_tables(np.random.RandomState(9), n - 1, nb, bs, lengths)
+    table[table == n - 1] = n                         # sentinel: "no block"
+    dead = np.setdiff1d(np.arange(n), table[table < n])
+    assert n - 1 in dead
+    kp_nan = kp.at[dead].set(jnp.nan)
+    vp_nan = vp.at[dead].set(jnp.nan)
+    tbl, lens = jnp.asarray(table), jnp.asarray(lengths)
+    out, _, _ = paged_decode_attention(q, nk, nv, kp_nan, vp_nan, tbl, lens)
+    outr, _, _ = paged_decode_attention_ref(q, nk, nv, kp, vp, tbl, lens)
+    assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(outr),
                                atol=1e-4, rtol=1e-4)
 

@@ -137,6 +137,17 @@ def test_tpu_backend_on_mesh_keeps_paged_decode_on_xla():
     assert plan.decode_cfg_overrides == {"rglru_impl": "pallas"}
 
 
+def test_tpu_backend_keeps_paged_decode_on_xla_for_heads_it_cannot_copy():
+    """The paged kernel copies whole pages out of the pool by hand, which the
+    TPU compiler refuses for hd 64 or an odd kv-head count in bf16: the plan
+    keeps such decode attention on XLA and says why."""
+    plan = ExecutionOracle(get_config("qwen2-0.5b"), backend="tpu",
+                           **GEOM).resolve()
+    assert plan.prefill_cfg_overrides == {"attn_impl": "pallas"}
+    assert plan.decode_cfg_overrides == {}
+    assert "cannot copy pages of 2 kv heads x 64" in plan.dumps()
+
+
 def test_overrides_are_runtime_safe():
     for arch in ("qwen3-0.6b", "recurrentgemma-2b", "falcon-mamba-7b"):
         plan = ExecutionOracle(get_config(arch), backend="tpu",

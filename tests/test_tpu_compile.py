@@ -119,6 +119,28 @@ def test_paged_decode_compiles(topo):
                                        table).compile())
 
 
+@pytest.mark.parametrize("kvh,hd", [(8, 128), (2, 256), (2, 64), (1, 128),
+                                    (3, 128)])
+def test_paged_decode_lowers_matches_the_compiler(topo, kvh, hd):
+    """``lowers`` (which keeps the placement off the kernel) agrees with the
+    compiler on bf16 head shapes either side of its rule."""
+    from repro.kernels.paged_attention.kernel import (
+        lowers, paged_decode_attention_raw)
+    one = SingleDeviceSharding(topo.devices[0])
+    nb = MAX_LEN // BLOCK
+    q = _sds((SLOTS, 2 * kvh, hd), jnp.bfloat16, one)
+    pool = _sds((SLOTS * nb, BLOCK, kvh, hd), jnp.bfloat16, one)
+    table = _sds((SLOTS, nb), jnp.int32, one)
+    lengths = _sds((SLOTS,), jnp.int32, one)
+    try:
+        jax.jit(paged_decode_attention_raw).lower(q, pool, pool, table,
+                                                  lengths).compile()
+        compiled = True
+    except Exception:  # noqa: BLE001 — Mosaic's refusal
+        compiled = False
+    assert compiled == lowers(kvh, hd, 2)
+
+
 @pytest.mark.parametrize("steps", [1, 128])
 def test_rglru_compiles(topo, steps):
     one = SingleDeviceSharding(topo.devices[0])

@@ -1,9 +1,10 @@
-"""The 10 assigned architecture configs (exact dims from the assignment table)
-plus ``reduced_config`` for CPU smoke tests.
+"""The assigned architecture configs (exact dims from the assignment table,
+and the published config of each added model) plus ``reduced_config`` for
+CPU smoke tests.
 
 Sources ([source; verified-tier] per assignment):
   recurrentgemma-2b   [arXiv:2402.19427; hf]   hybrid RG-LRU + local attn, 1:2
-  qwen3-0.6b          [hf:Qwen/Qwen3-8B; hf]   qk_norm, GQA
+  qwen3-0.6b          [hf:Qwen/Qwen3-0.6B; hf]   qk_norm, GQA
   starcoder2-7b       [arXiv:2402.19173; hf]   GQA, RoPE, layernorm+MLP
   smollm-135m         [hf:HuggingFaceTB/SmolLM-135M; hf]  llama-arch small
   qwen2-0.5b          [arXiv:2407.10671; hf]   GQA, QKV bias
@@ -12,6 +13,8 @@ Sources ([source; verified-tier] per assignment):
   llama4-scout-17b    [hf:meta-llama/Llama-4-Scout-17B-16E; unverified] 16e top-1
   seamless-m4t-medium [arXiv:2308.11596; hf]   enc-dec, audio-frontend stub
   falcon-mamba-7b     [arXiv:2410.05355; unverified]  mamba1, attn-free
+  granite-4.0-h-micro [hf:ibm-granite/granite-4.0-h-micro; hf]  Mamba-2 +
+                      NoPE GQA attention (every 10th layer from the 6th)
 """
 from __future__ import annotations
 
@@ -95,10 +98,26 @@ FALCON_MAMBA_7B = ArchConfig(
     d_inner=8192, d_state=16, d_conv=4, dt_rank=256,
     tie_embeddings=True)
 
+# https://huggingface.co/ibm-granite/granite-4.0-h-micro/blob/main/config.json:
+# 40 layers, attention at layer_types indices 5, 15, 25, 35; mamba_n_heads 64
+# x mamba_d_head 64 (expand 2), d_state 128, 1 group, conv 4 with bias;
+# shared_intermediate_size 8192; attention_multiplier 1/64,
+# embedding_multiplier 12, residual_multiplier 0.22, logits_scaling 8
+GRANITE_4_0_H_MICRO = ArchConfig(
+    name="granite-4.0-h-micro", family="hybrid",
+    num_layers=40, d_model=2048, num_heads=32, num_kv_heads=8, head_dim=64,
+    d_ff=8192, vocab_size=100352,
+    block_pattern=("mamba2",) * 5 + ("attn",) + ("mamba2",) * 4,
+    ffn_kind="glu", activation="silu", norm="rms", norm_eps=1e-5,
+    use_rope=False, d_inner=4096, d_state=128, d_conv=4, ssm_heads=64,
+    ssm_groups=1, scan_chunk=256, embedding_multiplier=12.0,
+    attention_multiplier=1.0 / 64, residual_multiplier=0.22,
+    logits_scaling=8.0, tie_embeddings=True)
+
 ARCHS: dict[str, ArchConfig] = {c.name: c for c in [
     RECURRENTGEMMA_2B, QWEN3_0_6B, STARCODER2_7B, SMOLLM_135M, QWEN2_0_5B,
     INTERNVL2_2B, PHI35_MOE, LLAMA4_SCOUT, SEAMLESS_M4T_MEDIUM,
-    FALCON_MAMBA_7B]}
+    FALCON_MAMBA_7B, GRANITE_4_0_H_MICRO]}
 
 
 def get_config(name: str) -> ArchConfig:
@@ -139,6 +158,11 @@ def reduced_config(name: str) -> ArchConfig:
         kw["d_inner"] = 128
         kw["d_state"] = 4
         kw["dt_rank"] = 8
+    if c.ssm_heads:
+        # Mamba-2: 8 heads of 16 channels, the groups as published, so the
+        # heads still share B and C and each head's state is a matrix
+        kw["ssm_heads"] = 8
+        kw["d_state"] = 8
     if c.num_experts:
         kw["num_experts"] = 4
         kw["top_k"] = min(c.top_k, 2)

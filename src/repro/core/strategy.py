@@ -110,7 +110,7 @@ def _block_specs(cfg: ArchConfig, tokens: int, batch: int) -> list[tuple[str, La
         out.append(("rec", LayerSpec(
             name="rec", kind=LayerKind.RGLRU, in_features=cfg.d_model,
             hidden=cfg.d_rnn, seq_len=seq, **B)))
-    if "ssm" in kinds:
+    if kinds & {"ssm", "mamba2"}:
         out.append(("ssm", LayerSpec(
             name="ssm", kind=LayerKind.SSM, in_features=cfg.d_model,
             hidden=cfg.d_inner, state=cfg.d_state, seq_len=seq, **B)))

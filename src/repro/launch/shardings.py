@@ -35,6 +35,10 @@ def _base_spec(names: list[str], leaf, is_moe: bool,
                dense_2d: bool = False) -> tuple:
     """PartitionSpec entries for the *unstacked* rank of this parameter."""
     name = names[-1]
+    if "mamba2" in names:
+        # the Mamba-2 mixer replicates: its input projection packs z, x, B,
+        # C and dt along one axis, which no head split divides evenly
+        return (None,) * leaf_rank(leaf)
     in_moe = is_moe and "ffn" in names and "shared" not in names
     # --- Jacquard cluster: big tables / expert banks, sharded & stationary
     if name in ("embed", "lm_head"):
@@ -229,6 +233,10 @@ def state_specs(model: Model, mesh: Mesh, batch: int, max_len: int) -> PyTree:
             return BlockState(rec={
                 "conv": P(*pad, b, None, "model"),
                 "h": h})
+        if kind == "mamba2":
+            return BlockState(rec={
+                "conv": P(*pad, b, None, None),
+                "h": P(*pad, b, "model", None, None)})
         raise ValueError(kind)
 
     groups = {}
@@ -293,6 +301,10 @@ def serve_state_specs(model: Model, mesh: Mesh, slots: int, max_len: int, *,
             return BlockState(rec={
                 "conv": P(*pad, b, None, wax(cfg.d_inner)),
                 "h": P(*pad, b, wax(cfg.d_inner), None)})
+        if kind == "mamba2":
+            return BlockState(rec={
+                "conv": P(*pad, b, None, None),
+                "h": P(*pad, b, wax(cfg.ssm_heads), None, None)})
         raise ValueError(kind)
 
     groups = {}

@@ -17,13 +17,22 @@ class ArchConfig:
     d_ff: int
     vocab_size: int
     # block structure: per-layer kind cycles through this pattern
-    block_pattern: tuple[str, ...] = ("attn",)   # attn|local|rec|ssm|dec
+    block_pattern: tuple[str, ...] = ("attn",)   # attn|local|rec|ssm|mamba2|dec
     ffn_kind: str = "glu"             # glu|mlp|moe|none
     activation: str = "silu"
     norm: str = "rms"                 # rms|layer
     qk_norm: bool = False
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    use_rope: bool = True             # False: no positional encoding (NoPE)
+    norm_eps: float = 1e-6            # RMSNorm epsilon
+    # residual-stream multipliers (Granite); the defaults are the plain
+    # transformer: embeddings x sqrt(d_model), scores x 1/sqrt(head_dim),
+    # branches added as they are, logits as they are
+    embedding_multiplier: float = 0.0   # 0: sqrt(d_model)
+    attention_multiplier: float = 0.0   # 0: 1/sqrt(head_dim)
+    residual_multiplier: float = 1.0    # each residual branch is scaled by it
+    logits_scaling: float = 1.0         # logits are divided by it
     window: int = 0                   # sliding-window size for "local" blocks
     # MoE
     num_experts: int = 0
@@ -38,6 +47,8 @@ class ArchConfig:
     d_state: int = 16
     d_conv: int = 4
     dt_rank: int = 0
+    ssm_heads: int = 0                # Mamba-2 heads (head dim d_inner/heads)
+    ssm_groups: int = 1               # Mamba-2 B/C groups shared by heads
     # encoder-decoder
     enc_layers: int = 0               # >0 => encoder-decoder (dec uses num_layers)
     # modality frontend stub (assignment: precomputed frame/patch embeddings)
@@ -77,7 +88,8 @@ class ArchConfig:
     def sub_quadratic(self) -> bool:
         """True when no block needs a full-length dense KV cache — the
         assignment's criterion for running long_500k."""
-        return all(k in ("rec", "ssm", "local") for k in set(self.layer_kinds))
+        return all(k in ("rec", "ssm", "mamba2", "local")
+                   for k in set(self.layer_kinds))
 
     @property
     def is_encdec(self) -> bool:
@@ -102,6 +114,11 @@ class ArchConfig:
                            + self.d_inner * (dtr + 2 * self.d_state)
                            + dtr * self.d_inner + self.d_inner * d
                            + (self.d_conv + self.d_state + 2) * self.d_inner)
+        conv_dim = self.d_inner + 2 * self.ssm_groups * self.d_state
+        per_kind["mamba2"] = (d * (conv_dim + self.d_inner + self.ssm_heads)
+                              + (self.d_conv + 1) * conv_dim
+                              + 3 * self.ssm_heads + self.d_inner
+                              + self.d_inner * d)
         if self.ffn_kind == "glu":
             ffn = 3 * d * self.d_ff
         elif self.ffn_kind == "mlp":

@@ -3,6 +3,8 @@
 * ``rglru_block`` — RecurrentGemma/Griffin recurrent block: temporal conv1d +
   RG-LRU gated linear recurrence, GeLU-gated output branch.
 * ``mamba_block`` — Mamba-1 selective SSM (Falcon-Mamba).
+* ``mamba2_block`` — Mamba-2 mixer (state-space duality, Granite 4.0-H):
+  chunked SSD in matmul form for prefill, a one-token recurrence for decode.
 * ``lstm_layer``  — classic LSTM (reference for the Pavlov kernels and the
   edge-model examples).
 
@@ -317,6 +319,164 @@ def mamba_block(params: dict, x: jax.Array, *, d_state: int = 16,
     if return_state:
         return out, {"conv": new_conv, "h": h_last}
     return out
+
+
+# ---------------------------------------------------------------------- Mamba-2
+def init_mamba2_block(key, d_model: int, d_inner: int, n_heads: int,
+                      d_state: int, n_groups: int = 1, d_conv: int = 4,
+                      dtype=jnp.float32) -> dict:
+    """Mamba-2 mixer weights.  ``in_proj`` maps to [z, x, B, C, dt]
+    (d_inner, d_inner, groups*d_state twice, n_heads); the depthwise conv
+    (with bias) runs over [x, B, C]; ``norm`` is the gated RMSNorm's
+    weight, stored as ``1 + norm`` like every RMSNorm here."""
+    conv_dim = d_inner + 2 * n_groups * d_state
+    ks = jax.random.split(key, 5)
+    dt = jnp.exp(jax.random.uniform(ks[2], (n_heads,), jnp.float32,
+                                    math.log(1e-3), math.log(1e-1)))
+    a = jax.random.uniform(ks[3], (n_heads,), jnp.float32, 1.0, 16.0)
+    return {
+        "in_proj": fan_in_init(ks[0], (d_model, 2 * d_inner
+                                       + 2 * n_groups * d_state + n_heads),
+                               dtype),
+        "conv_w": normal_init(ks[1], (d_conv, conv_dim),
+                              1.0 / math.sqrt(d_conv), dtype),
+        "conv_b": jnp.zeros((conv_dim,), dtype),
+        "dt_bias": jnp.log(jnp.expm1(dt)).astype(dtype),
+        "a_log": jnp.log(a).astype(dtype),
+        "d_skip": jnp.ones((n_heads,), dtype),
+        "norm": jnp.zeros((d_inner,), dtype),
+        "out_proj": fan_in_init(ks[4], (d_inner, d_model), dtype),
+    }
+
+
+def ssd_chunked(x, dt, a, b, c, h0, chunk: int):
+    """Chunked state-space duality (SSD) scan in matmul form.
+
+    x: (B,S,G,E,P) inputs, dt: (B,S,G,E) step sizes (0 where masked),
+    a: (G,E) negative decay rates, b/c: (B,S,G,N), h0: (B,G,E,P,N), all
+    float32; G groups of E heads of P channels share B and C.  Returns
+    (y (B,S,G,E,P), h_last).  With a_t = dt_t * a the recurrence is
+    h_t = exp(a_t) h_{t-1} + dt_t x_t B_t^T, y_t = h_t C_t.  Within a
+    chunk of Q steps, with A_i the cumulative sum of a_t up to step i,
+
+      y_i = sum_{j<=i} (C_i . B_j) exp(A_i - A_j) dt_j x_j + exp(A_i) h C_i
+
+    (the masked quadratic form, then the carried state ``h`` entering the
+    chunk), and the state leaving it is
+    exp(A_Q) h + sum_j exp(A_Q - A_j) dt_j x_j B_j^T.  A lax.scan carries h
+    from chunk to chunk.  S need not divide ``chunk``: the tail is padded
+    with dt = 0 steps, which leave the state unchanged."""
+    B_, S = x.shape[:2]
+    q = min(chunk, S)
+    pad = (-S) % q
+    if pad:
+        def padt(t):
+            return jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        x, dt, b, c = padt(x), padt(dt), padt(b), padt(c)
+    nc = (S + pad) // q
+
+    def chunks(t):                       # (B, S, ...) -> (B, nc, Q, ...)
+        return t.reshape((B_, nc, q) + t.shape[2:])
+
+    x, dt, b, c = chunks(x), chunks(dt), chunks(b), chunks(c)
+    acs = jnp.cumsum(dt * a, axis=2)                         # (B,nc,Q,G,E)
+    # intra-chunk decay exp(A_i - A_j), i >= j, as (B,nc,G,E,i,j)
+    at = jnp.moveaxis(acs, 2, -1)                            # (B,nc,G,E,Q)
+    seg = at[..., :, None] - at[..., None, :]
+    causal = jnp.tril(jnp.ones((q, q), bool))
+    decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
+    cb = jnp.einsum("bcign,bcjgn->bcgij", c, b)              # (B,nc,G,Q,Q)
+    w = cb[:, :, :, None] * decay * jnp.moveaxis(dt, 2, -1)[..., None, :]
+    y = jnp.einsum("bcgeij,bcjgep->bcigep", w, x)
+    # each chunk's own contribution to the state at its end
+    to_end = jnp.exp(acs[:, :, -1:] - acs) * dt               # (B,nc,Q,G,E)
+    states = jnp.einsum("bcjgn,bcjge,bcjgep->bcgepn", b, to_end, x)
+    chunk_decay = jnp.exp(acs[:, :, -1])                      # (B,nc,G,E)
+
+    def carry(h, inp):
+        st, dec = inp
+        return dec[..., None, None] * h + st, h
+
+    h_last, h_in = jax.lax.scan(
+        carry, h0, (jnp.moveaxis(states, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
+    h_in = jnp.moveaxis(h_in, 0, 1)                          # (B,nc,G,E,P,N)
+    y = y + jnp.einsum("bcign,bcgepn->bcigep", c, h_in) \
+        * jnp.exp(acs)[..., None]
+    y = y.reshape((B_, nc * q) + y.shape[3:])[:, :S]
+    return y, h_last
+
+
+def ssd_step(x, dt, a, b, c, h0):
+    """One token of the SSD recurrence.  x: (B,G,E,P), dt: (B,G,E),
+    a: (G,E), b/c: (B,G,N), h0: (B,G,E,P,N).  Returns (y, h).  A row with
+    dt = 0 keeps its state bit for bit."""
+    h = jnp.exp(dt * a)[..., None, None] * h0 \
+        + (dt[..., None] * x)[..., None] * b[:, :, None, None, :]
+    y = jnp.sum(h * c[:, :, None, None, :], axis=-1)
+    return y, h
+
+
+def mamba2_block(params: dict, x: jax.Array, *, n_heads: int, d_state: int,
+                 n_groups: int = 1, chunk: int = 256, eps: float = 1e-6,
+                 state: dict | None = None, return_state: bool = False,
+                 length: jax.Array | None = None):
+    """Mamba-2 mixer.  x: (B,S,D) -> (B,S,D).
+
+      [z, xBC, dt] = x W_in
+      [x, B, C]    = silu(conv1d(xBC) + conv_b)         (causal, width K)
+      dt           = softplus(dt + dt_bias),  a = -exp(a_log)   (per head)
+      h_t          = exp(dt_t a) h_{t-1} + dt_t x_t B_t^T       (per head:
+                                                             P x N state)
+      y_t          = h_t C_t + D x_t
+      out          = (rmsnorm(y * silu(z)) * (1 + norm)) W_out
+
+    Prefill runs the recurrence as chunked SSD (:func:`ssd_chunked`),
+    decode (S = 1) as one step (:func:`ssd_step`); both in float32.
+    ``state``: {"conv": (B,K-1,conv_dim), "h": (B,H,P,N)} carried in;
+    ``length``: (B,) valid prefix lengths of right-padded x — positions past
+    it get dt = 0, so the returned state is the state at length-1 (and a
+    decode row with length 0 keeps its state)."""
+    with jax.named_scope("mamba2"):
+        dt_ = x.dtype
+        f32 = jnp.float32
+        B_, S, _ = x.shape
+        d_inner = params["out_proj"].shape[0]
+        gn = n_groups * d_state
+        p_dim = d_inner // n_heads
+        e = n_heads // n_groups
+        proj = jnp.einsum("bsd,de->bse", x, params["in_proj"].astype(dt_))
+        z = proj[..., :d_inner]
+        xbc = proj[..., d_inner:2 * d_inner + 2 * gn]
+        dt = proj[..., 2 * d_inner + 2 * gn:]
+        conv_state = state["conv"] if state else None
+        xbc, new_conv = causal_conv1d(xbc, params["conv_w"].astype(dt_),
+                                      conv_state, length=length)
+        xbc = jax.nn.silu(xbc.astype(f32) + params["conv_b"].astype(f32))
+        xs = xbc[..., :d_inner].reshape(B_, S, n_groups, e, p_dim)
+        b = xbc[..., d_inner:d_inner + gn].reshape(B_, S, n_groups, d_state)
+        c = xbc[..., d_inner + gn:].reshape(B_, S, n_groups, d_state)
+        dt = jax.nn.softplus(dt.astype(f32) + params["dt_bias"].astype(f32))
+        if length is not None:
+            live = jnp.arange(S)[None, :] < length[:, None]
+            dt = jnp.where(live[:, :, None], dt, 0.0)
+        dt = dt.reshape(B_, S, n_groups, e)
+        a = -jnp.exp(params["a_log"].astype(f32)).reshape(n_groups, e)
+        h0 = state["h"].reshape(B_, n_groups, e, p_dim, d_state) if state \
+            else jnp.zeros((B_, n_groups, e, p_dim, d_state), f32)
+        if S == 1:
+            y, h_last = ssd_step(xs[:, 0], dt[:, 0], a, b[:, 0], c[:, 0], h0)
+            y = y[:, None]
+        else:
+            y, h_last = ssd_chunked(xs, dt, a, b, c, h0, chunk)
+        y = y + xs * params["d_skip"].astype(f32).reshape(n_groups, e, 1)
+        y = y.reshape(B_, S, d_inner) * jax.nn.silu(z.astype(f32))
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps)
+        y = (y * (1.0 + params["norm"].astype(f32))).astype(dt_)
+        out = jnp.einsum("bse,ed->bsd", y, params["out_proj"].astype(dt_))
+        if return_state:
+            return out, {"conv": new_conv,
+                         "h": h_last.reshape(B_, n_heads, p_dim, d_state)}
+        return out
 
 
 # ------------------------------------------------------------------------ LSTM

@@ -13,6 +13,7 @@ Three entry points per model:
 """
 from __future__ import annotations
 
+import math
 from typing import Any, NamedTuple
 
 import jax
@@ -33,7 +34,7 @@ PyTree = Any
 # ----------------------------------------------------------------------- norms
 def _norm(cfg: ArchConfig, p: dict, x: jax.Array) -> jax.Array:
     if cfg.norm == "rms":
-        return rms_norm(x, p["scale"])
+        return rms_norm(x, p["scale"], cfg.norm_eps)
     return layer_norm(x, p["scale"], p["bias"])
 
 
@@ -65,6 +66,10 @@ def _init_block(cfg: ArchConfig, kind: str, key, dtype) -> dict:
             ks[0], cfg.d_model, cfg.d_inner, cfg.d_state, cfg.d_conv,
             cfg.dt_rank or None, dtype=dtype)
         return p                                  # Mamba block has no FFN
+    elif kind == "mamba2":
+        p["mamba2"] = rec_lib.init_mamba2_block(
+            ks[0], cfg.d_model, cfg.d_inner, cfg.ssm_heads, cfg.d_state,
+            cfg.ssm_groups, cfg.d_conv, dtype=dtype)
     else:
         raise ValueError(kind)
     if cfg.ffn_kind == "glu":
@@ -90,6 +95,15 @@ class BlockState(NamedTuple):
     cross_kv: tuple | None = None      # (k, v) from encoder memory
 
 
+def _residual(cfg: ArchConfig, x: jax.Array, y: jax.Array) -> jax.Array:
+    """x + a residual branch y, scaled by ``residual_multiplier`` (in
+    float32, so the multiplier is not rounded to the activations' dtype)."""
+    if cfg.residual_multiplier == 1.0:
+        return x + y
+    return (x.astype(jnp.float32) + y.astype(jnp.float32)
+            * cfg.residual_multiplier).astype(x.dtype)
+
+
 def _attn_ffn_tail(cfg, p, x):
     """Returns (x, load_balance_aux) — aux flows through scan carries."""
     h = _norm(cfg, p["ln2"], x)
@@ -98,12 +112,14 @@ def _attn_ffn_tail(cfg, p, x):
                                      capacity_factor=cfg.moe_capacity,
                                      activation=cfg.activation,
                                      return_aux=True, impl=cfg.moe_impl)
-        return x + y, moe_aux["load_balance"]
+        return _residual(cfg, x, y), moe_aux["load_balance"]
     zero = jnp.zeros((), jnp.float32)
     if cfg.ffn_kind == "glu":
-        return x + ffn_lib.glu_ffn(p["ffn"], h, cfg.activation), zero
+        return _residual(cfg, x, ffn_lib.glu_ffn(p["ffn"], h, cfg.activation)
+                         ), zero
     if cfg.ffn_kind == "mlp":
-        return x + ffn_lib.mlp_ffn(p["ffn"], h, cfg.activation), zero
+        return _residual(cfg, x, ffn_lib.mlp_ffn(p["ffn"], h, cfg.activation)
+                         ), zero
     return x, zero
 
 
@@ -139,7 +155,13 @@ def apply_block(cfg: ArchConfig, kind: str, p: dict, x: jax.Array,
         h = _norm(cfg, p["ln1"], x)
         q, k, v = attn_lib.qkv_project(
             p["attn"], h, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-            positions, rope_theta=cfg.rope_theta, use_rope=(kind != "enc"))
+            positions, rope_theta=cfg.rope_theta,
+            use_rope=cfg.use_rope and kind != "enc")
+        if cfg.attention_multiplier:
+            # every attention core scales scores by 1/sqrt(head_dim): fold
+            # the model's own multiplier into q instead
+            q = (q * (cfg.attention_multiplier * math.sqrt(cfg.head_dim))
+                 ).astype(q.dtype)
         if mode == "decode":
             wm = None if length is None else length > 0
             if paged:
@@ -198,7 +220,8 @@ def apply_block(cfg: ArchConfig, kind: str, p: dict, x: jax.Array,
             new_state = state._replace(kv=kv)
         b, s, _, _ = out.shape
         o = out.reshape(b, s, cfg.num_heads * cfg.head_dim)
-        x = x + jnp.einsum("bsh,hd->bsd", o, p["attn"]["wo"].astype(x.dtype))
+        x = _residual(cfg, x, jnp.einsum("bsh,hd->bsd", o,
+                                         p["attn"]["wo"].astype(x.dtype)))
         if kind == "dec":
             hx = _norm(cfg, p["ln_x"], x)
             qx, _, _ = attn_lib.qkv_project(
@@ -249,6 +272,20 @@ def apply_block(cfg: ArchConfig, kind: str, p: dict, x: jax.Array,
                 length=length)
             x = x + y
             new_state = state._replace(rec=rec_state)
+    elif kind == "mamba2":
+        h = _norm(cfg, p["ln1"], x)
+        kw = dict(n_heads=cfg.ssm_heads, d_state=cfg.d_state,
+                  n_groups=cfg.ssm_groups, chunk=cfg.scan_chunk,
+                  eps=cfg.norm_eps)
+        if mode == "train":
+            y = rec_lib.mamba2_block(p["mamba2"], h, **kw)
+        else:
+            y, rec_state = rec_lib.mamba2_block(
+                p["mamba2"], h, state=_resume_rec(state.rec, offset),
+                return_state=True, length=length, **kw)
+            new_state = state._replace(rec=rec_state)
+        x = _residual(cfg, x, y)
+        x, lb = _attn_ffn_tail(cfg, p, x)
     else:
         raise ValueError(kind)
     return x, new_state, lb
@@ -296,6 +333,14 @@ def _fill_cache(cache: attn_lib.KVCache, k, v, window: int = 0,
                                       (0, 0, 0, 0))
     new_len = cache.length + (s if length is None else length)
     return attn_lib.KVCache(ck, cv, new_len)
+
+
+def _logits(cfg: ArchConfig, x: jax.Array, table: jax.Array) -> jax.Array:
+    """fp32 logits over the real vocabulary, divided by ``logits_scaling``."""
+    logits = unembed(x, table)[..., :cfg.vocab_size]
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 # ------------------------------------------------------------------- the model
@@ -352,8 +397,10 @@ class Model:
     def _embed_inputs(self, params, tokens, modality=None):
         cfg = self.cfg
         dt = jnp.dtype(cfg.compute_dtype)
-        x = embed(params["embed"], tokens, dt) * jnp.sqrt(
-            jnp.asarray(cfg.d_model, dt))
+        x = embed(params["embed"], tokens, dt) * (
+            jnp.asarray(cfg.embedding_multiplier, dt)
+            if cfg.embedding_multiplier
+            else jnp.sqrt(jnp.asarray(cfg.d_model, dt)))
         if modality is not None and cfg.modality_tokens:
             m = modality.astype(dt)
             m = jnp.einsum("bmd,de->bme", m, params["mm_proj"]["w1"].astype(dt))
@@ -429,7 +476,7 @@ class Model:
         x, lb = self._run_stack(params, x, positions, memory)
         x = _norm(cfg, params["final_norm"], x)
         table = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-        logits = unembed(x, table)[..., :cfg.vocab_size]
+        logits = _logits(cfg, x, table)
         if cfg.modality_tokens and modality is not None:
             logits = logits[:, modality.shape[1]:]
         aux = {"load_balance": lb} if cfg.ffn_kind == "moe" else {}
@@ -498,6 +545,13 @@ class Model:
                     "conv": jnp.zeros((batch, cfg.d_conv - 1, cfg.d_inner), dt),
                     "h": jnp.zeros((batch, cfg.d_inner, cfg.d_state),
                                    jnp.float32)})
+            if kind == "mamba2":
+                conv_dim = cfg.d_inner + 2 * cfg.ssm_groups * cfg.d_state
+                return BlockState(rec={
+                    "conv": jnp.zeros((batch, cfg.d_conv - 1, conv_dim), dt),
+                    "h": jnp.zeros((batch, cfg.ssm_heads,
+                                    cfg.d_inner // cfg.ssm_heads,
+                                    cfg.d_state), jnp.float32)})
             raise ValueError(kind)
 
         groups = {}
@@ -610,7 +664,7 @@ class Model:
             x_last = jnp.take_along_axis(
                 x, jnp.broadcast_to(idx, (x.shape[0], 1, x.shape[2])), axis=1)
         table = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-        logits = unembed(x_last, table)[..., :cfg.vocab_size]
+        logits = _logits(cfg, x_last, table)
         return logits, states, memory
 
     def decode_step(self, params, token, states, position, memory=None,
@@ -639,7 +693,7 @@ class Model:
                                             layout=layout)
         x = _norm(cfg, params["final_norm"], x)
         table = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-        logits = unembed(x, table)[..., :cfg.vocab_size]
+        logits = _logits(cfg, x, table)
         return logits, states
 
 

@@ -189,6 +189,14 @@ class EngineStats:
     evict_scanned: int = 0
     reclaim_walked: int = 0
     decode_stalls: int = 0              # slot-ticks frozen waiting for blocks
+    # ---- recurrent slot state (conv + RG-LRU/SSM/Mamba-2 carries) ----
+    # {kind: {"layers", "bytes_per_slot"}}; empty for pure-attention stacks
+    rec_state_kinds: dict = field(default_factory=dict)
+    rec_state_slots: int = 0            # slots the state is held for
+    rec_slots_live: int = 0             # slots holding a request's state,
+    #                                     end of last tick
+    rec_chunks_resumed: int = 0         # chunk calls that carried a state in
+    prefix_cache_note: str = ""         # why the prefix cache is off
     # ---- sharded pool (mesh engines; kv_shards == 1 otherwise) ----
     kv_shards: int = 1
     kv_in_use_per_shard: list = field(default_factory=list)
@@ -273,6 +281,20 @@ class EngineStats:
                 out["kv"]["shards"] = self.kv_shards
                 out["kv"]["in_use_per_shard"] = list(self.kv_in_use_per_shard)
                 out["kv"]["peak_per_shard"] = list(self.kv_peak_per_shard)
+        if self.rec_state_kinds:
+            kinds = {k: {**v, "bytes": v["bytes_per_slot"]
+                         * self.rec_state_slots}
+                     for k, v in self.rec_state_kinds.items()}
+            per_slot = sum(v["bytes_per_slot"] for v in kinds.values())
+            out["state"] = {
+                "kinds": kinds,
+                "bytes_per_slot": per_slot,
+                "bytes": per_slot * self.rec_state_slots,
+                "slots_live": self.rec_slots_live,
+                "chunks_resumed": self.rec_chunks_resumed,
+            }
+            if self.prefix_cache_note:
+                out["state"]["prefix_cache"] = self.prefix_cache_note
         if self.placement:
             # plan (predicted) + measured + drift, side by side — the triple
             # benchmarks/calibrate.py fits the cost model against (same
@@ -455,6 +477,7 @@ class ServeEngine:
         # ------------------------------------------------- paged KV pool
         self.kv: PagedKVManager | None = None
         self._state_kw: dict = {}
+        self._prefix_note = ""
         if kv_block_size is not None:
             blocks_per_slot = -(-max_len // kv_block_size)
             if kv_blocks is None:
@@ -472,6 +495,11 @@ class ServeEngine:
             # recurrent states are not block-addressable)
             kinds = tuple(model.pattern) + tuple(model.tail_kinds)
             prefix_ok = bool(kinds) and all(k == "attn" for k in kinds)
+            if prefix_cache and not prefix_ok:
+                self._prefix_note = (
+                    "off: the prefix cache shares KV blocks only; "
+                    f"{sorted(set(kinds) - {'attn'})} layers carry per-slot "
+                    "state that is not snapshotted at block boundaries")
             # the device pool shards its block axis over the data axes only
             # when the stripes come out equal — the host-side accounting
             # mirrors exactly that layout
@@ -570,11 +598,14 @@ class ServeEngine:
         # paged K/V leaves belong to the pool, everything else to the slots
         pool_bytes, state_bytes = self._state_byte_stats()
         self._slot_state_bytes = state_bytes // slots
+        self._rec_kinds = self._rec_state_bytes()
         if self.kv is not None:
             self.kv.set_block_bytes(pool_bytes // self.kv.pool.num_blocks)
         self.stats = EngineStats()
-        # the KV manager's work counters at the last counter sample
+        # the KV manager's work counters at the last counter sample, and the
+        # resumed chunk calls
         self._kv_work_seen = (0, 0)
+        self._rec_resumed_seen = 0
         self._init_kv_stats()
 
     def _span(self, name: str, tid: int | None = None, args: tuple = ()):
@@ -598,7 +629,32 @@ class ServeEngine:
                 state_b += leaf.nbytes
         return pool_b, state_b
 
+    def _rec_state_bytes(self) -> dict:
+        """Recurrent slot state (conv and recurrence carries) by block kind:
+        {kind: {"layers", "bytes_per_slot"}}, read off the state tree."""
+        out: dict = {}
+
+        def add(kind, st, layers):
+            if st.rec is None:
+                return
+            b = sum(a.nbytes for a in jax.tree.leaves(st.rec)) // self.slots
+            e = out.setdefault(kind, {"layers": 0, "bytes_per_slot": 0})
+            e["layers"] += layers
+            e["bytes_per_slot"] += b
+
+        m = self.model
+        if m.n_groups > 0:
+            for j, kind in enumerate(m.pattern):
+                add(kind, self.states["groups"][str(j)], m.n_groups)
+        for kind, st in zip(m.tail_kinds, self.states["tail"]):
+            add(kind, st, 1)
+        return out
+
     def _init_kv_stats(self) -> None:
+        self.stats.rec_state_kinds = {k: dict(v)
+                                      for k, v in self._rec_kinds.items()}
+        self.stats.rec_state_slots = self.slots
+        self.stats.prefix_cache_note = self._prefix_note
         if self.kv is not None:
             self.stats.kv_pool_blocks = self.kv.pool.num_blocks
             self.stats.kv_block_size = self.kv.block_size
@@ -618,6 +674,7 @@ class ServeEngine:
     def reset_stats(self) -> None:
         self.stats = EngineStats()
         self._kv_work_seen = (0, 0)
+        self._rec_resumed_seen = 0
         if self.kv is not None:
             self.kv.reset_stats()
         self.programs.reset_observed()
@@ -659,10 +716,14 @@ class ServeEngine:
     def _tick_counters(self, ts: float, busy: int) -> None:
         """Per-tick counter-track samples (queue depth, slot occupancy,
         paged KV-pool in-use/cached and the radix nodes the KV manager
-        scanned or walked since the last sample, device-memory bytes) plus
-        the memory gauges — gauges update even untraced so ``summary()``
-        always carries the latest occupancy in bytes."""
+        scanned or walked since the last sample, slots holding recurrent
+        state and the chunk calls that resumed one since the last sample,
+        device-memory bytes) plus the memory gauges — gauges update even
+        untraced so ``summary()`` always carries the latest occupancy in
+        bytes."""
         m = self.stats.metrics
+        if self._rec_kinds:
+            self.stats.rec_slots_live = busy
         state_bytes = busy * self._slot_state_bytes
         m.gauge("active_state_bytes", "bytes").set(state_bytes)
         if self.kv is not None:
@@ -685,6 +746,12 @@ class ServeEngine:
                 tr.counter(p + "kv_in_use_by_shard", ts, tuple(
                     (f"shard{i}", v)
                     for i, v in enumerate(self.kv.in_use_by_shard)))
+        if self._rec_kinds:
+            resumed = self.stats.rec_chunks_resumed
+            seen, self._rec_resumed_seen = self._rec_resumed_seen, resumed
+            tr.counter(p + "rec_state", ts, (("live_slots", busy),
+                                             ("chunks_resumed",
+                                              resumed - seen)))
         series = [("slot_state", state_bytes)]
         if self.kv is not None:
             series.append(("kv_pool", self.kv.bytes_in_use))
@@ -1047,6 +1114,8 @@ class ServeEngine:
             tok = tm.sync(tok)
         st = self.stats
         st.prefill_chunks += 1
+        if off > 0 and self._rec_kinds:
+            st.rec_chunks_resumed += 1
         st.prefill_padded_tokens += c
         st.prefill_tokens_computed += n
         st.prefill_time_s += tm.dur

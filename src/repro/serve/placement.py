@@ -51,6 +51,7 @@ BYTES = 2.0  # serving runs bf16 activations/params (matches core/strategy.py)
 # pavlov_ssm kernel returns outputs only (no final state), and serving
 # prefill must hand the scan state to decode — the oracle therefore keeps
 # SSM blocks on the XLA scan and records the reason on the policy.
+# "mamba2" has no kernel at all: its mixer is XLA chunked SSD.
 KIND_TO_IMPL_KNOB = {
     "attn": "attn_impl",
     "local": "attn_impl",
@@ -245,8 +246,8 @@ class ExecutionOracle:
             return LayerSpec(name="rec", kind=LayerKind.RGLRU,
                              in_features=cfg.d_model, hidden=cfg.d_rnn,
                              seq_len=seq, **B)
-        if kind == "ssm":
-            return LayerSpec(name="ssm", kind=LayerKind.SSM,
+        if kind in ("ssm", "mamba2"):
+            return LayerSpec(name=kind, kind=LayerKind.SSM,
                              in_features=cfg.d_model, hidden=cfg.d_inner,
                              state=cfg.d_state, seq_len=seq, **B)
         if kind == "ffn":
@@ -303,10 +304,15 @@ class ExecutionOracle:
         pallas_ok = backend == "tpu"
         knobs = sorted({KIND_TO_IMPL_KNOB[k] for k in kinds
                         if k in KIND_TO_IMPL_KNOB})
+        xla_only = {
+            "ssm": "ssm kernel yields no carry state — serving stays on "
+                   "the XLA scan",
+            "mamba2": "mamba2 has no kernel — its mixer runs as XLA "
+                      "chunked SSD in prefill and one recurrence step in "
+                      "decode"}
         if not knobs:
-            reason = ("ssm kernel yields no carry state — serving stays on "
-                      "the XLA scan" if "ssm" in kinds else "")
-            return "xla", {}, [], reason
+            return "xla", {}, [], "; ".join(
+                why for k, why in xla_only.items() if k in kinds)
         if not pallas_ok:
             return "xla", {}, [], \
                 f"backend {backend!r} lowers via XLA reference path"
@@ -346,7 +352,7 @@ class ExecutionOracle:
         """Recurrent clusters bound the per-tick scan length (decode latency
         for running slots is gated on one chunk's scan); everything else
         takes the widest chunk (fewest chunk program invocations)."""
-        if any(k in ("rec", "ssm") for k in cluster_kinds):
+        if any(k in ("rec", "ssm", "mamba2") for k in cluster_kinds):
             return max(self.min_bucket, min(ladder_top, self.cfg.scan_chunk))
         return ladder_top
 

@@ -104,6 +104,7 @@ def test_full_config_structure(arch):
         "llama4-scout-17b-a16e": (48, 5120, 40, 8, 8192, 202048),
         "seamless-m4t-medium": (12, 1024, 16, 16, 4096, 256206),
         "falcon-mamba-7b": (64, 4096, 0, 0, 0, 65024),
+        "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100352),
     }
     layers, d, h, kv, dff, vocab = table[arch]
     assert cfg.num_layers == layers

@@ -108,7 +108,7 @@ def test_gap_bucket_not_divisible_by_scan_chunk_on_recurrent_arch():
 
 # ----------------------------------------------- splice vs batch-1 reference
 @pytest.mark.parametrize("arch", ["qwen3-0.6b", "recurrentgemma-2b",
-                                  "falcon-mamba-7b"])
+                                  "falcon-mamba-7b", "granite-4.0-h-micro"])
 def test_bucketed_prefill_matches_unpadded_reference(arch):
     """Engine output (padded/bucketed prefill spliced into the pool) must
     reproduce the manual unpadded batch-1 prefill + decode token-for-token —
@@ -159,7 +159,7 @@ def test_padded_prefill_logits_and_states_exact():
 
 # ---------------------------------------------------------- batched prefill
 @pytest.mark.parametrize("arch", ["qwen3-0.6b", "recurrentgemma-2b",
-                                  "falcon-mamba-7b"])
+                                  "falcon-mamba-7b", "granite-4.0-h-micro"])
 def test_batched_prefill_matches_sequential(arch):
     """Same-bucket admissions stacked into one (N, bucket) prefill call must
     generate exactly what N sequential batch-1 prefills generate, with fewer
@@ -225,7 +225,7 @@ def test_batch_bucket_padding_rows_are_inert():
 
 # ---------------------------------------------------------- chunked prefill
 @pytest.mark.parametrize("arch", ["qwen3-0.6b", "recurrentgemma-2b",
-                                  "falcon-mamba-7b"])
+                                  "falcon-mamba-7b", "granite-4.0-h-micro"])
 def test_chunked_prefill_matches_unchunked(arch):
     """A prompt longer than the largest bucket prefills in chunk-continuation
     calls (here 16+16+13) and must generate token-for-token what a one-shot
@@ -247,7 +247,7 @@ def test_chunked_prefill_matches_unchunked(arch):
 
 
 @pytest.mark.parametrize("arch", ["qwen3-0.6b", "recurrentgemma-2b",
-                                  "falcon-mamba-7b"])
+                                  "falcon-mamba-7b", "granite-4.0-h-micro"])
 def test_prefill_offset_continuation_matches_full(arch):
     """Model-level: prefill resumed via ``offset`` (ragged final chunk,
     right-padded) reproduces the one-shot prefill — last-position logits and
@@ -335,7 +335,8 @@ def test_decode_active_mask_freezes_state_bitwise():
     """Model-level: a decode step with active=False must leave every state
     leaf (KV contents + length, conv context, recurrent h) bit-for-bit
     unchanged, and active=True rows must match active=None bitwise."""
-    for arch in ["qwen3-0.6b", "recurrentgemma-2b", "falcon-mamba-7b"]:
+    for arch in ["qwen3-0.6b", "recurrentgemma-2b", "falcon-mamba-7b",
+                 "granite-4.0-h-micro"]:
         _, model, params = _tiny_model(arch)
         states = model.init_states(2, 32)
         toks = jnp.asarray([[5, 9, 2], [7, 1, 4]], jnp.int32)

@@ -28,7 +28,8 @@ from repro.serve.kvpool import (KVBlockPool, PagedKVManager, RadixPrefixCache,
                                 blocks_for)
 from repro.serve.sampling import sample_tokens
 
-ARCHS = ["qwen3-0.6b", "recurrentgemma-2b", "falcon-mamba-7b"]
+ARCHS = ["qwen3-0.6b", "recurrentgemma-2b", "falcon-mamba-7b",
+         "granite-4.0-h-micro"]
 
 
 def _tiny_model(arch="qwen3-0.6b", layers=2):
@@ -393,7 +394,8 @@ def test_finish_never_publishes_the_unwritten_last_token():
 def test_prefix_cache_disabled_on_non_attention_models():
     """Hybrid/recurrent stacks have state the block pool can't share — the
     prefix cache must disable itself rather than corrupt outputs."""
-    for arch in ["recurrentgemma-2b", "falcon-mamba-7b"]:
+    for arch in ["recurrentgemma-2b", "falcon-mamba-7b",
+                 "granite-4.0-h-micro"]:
         _, model, params = _tiny_model(arch)
         eng = ServeEngine(model, params, slots=1, max_len=32, kv_block_size=8,
                           prefix_cache=True)

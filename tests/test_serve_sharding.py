@@ -14,7 +14,8 @@ from repro.models import build_model
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.kvpool import KVBlockPool, PagedKVManager, RadixPrefixCache
 
-ARCHS = ("qwen3-0.6b", "recurrentgemma-2b", "falcon-mamba-7b")
+ARCHS = ("qwen3-0.6b", "recurrentgemma-2b", "falcon-mamba-7b",
+         "granite-4.0-h-micro")
 
 
 # ------------------------------------------------------------------ specs
@@ -28,8 +29,7 @@ def test_serve_state_specs_mirror_init_states(arch, paged):
     model = build_model(cfg)
     kw = {}
     if paged:
-        if any(k != "attn" for k in
-               tuple(model.pattern) + tuple(model.tail_kinds)):
+        if "attn" not in tuple(model.pattern) + tuple(model.tail_kinds):
             pytest.skip("paged KV covers full-attention layers")
         kw = dict(kv_block_size=16, kv_blocks=8)
     mesh = make_serve_mesh(1, 1)
